@@ -13,6 +13,7 @@ from __future__ import annotations
 from collections import defaultdict
 
 from benchmarks.common import WORKLOADS, run_workloads
+from repro.compile_cache import enable_compile_cache
 
 
 def summarize(rows):
@@ -87,4 +88,5 @@ def main(**kw):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 
 from _util import write_bench_json
+from repro.compile_cache import enable_compile_cache
 from repro.core import hnsw
 from repro.core.backend import SearchParams
 from repro.core.index import LSMVecIndex, brute_force_knn, recall_at_k
@@ -230,4 +231,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     sys.exit(main())

@@ -12,6 +12,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from benchmarks.common import DISK, default_cfg
+from repro.compile_cache import enable_compile_cache
 from repro.core import iostats
 from repro.core.backend import SearchParams
 from repro.core.baselines import DiskANNIndex, SPFreshIndex
@@ -73,4 +74,5 @@ def main(n_base: int = 4096, dim: int = 64, n_queries: int = 64):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -308,6 +308,8 @@ def main(path: str = "results/dryrun.json"):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if len(sys.argv) > 1 and sys.argv[1] == "beam":
         raise SystemExit(beam_main(sys.argv[2:]))
     main(sys.argv[1] if len(sys.argv) > 1 else "results/dryrun.json")

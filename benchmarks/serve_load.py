@@ -74,6 +74,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import jax
 
 from _util import write_bench_json
+from repro.compile_cache import enable_compile_cache
 from repro.core import hnsw
 from repro.core.backend import SearchParams, shard_of_seq
 from repro.core.distributed import ShardedBackend, ShardedDispatch
@@ -1077,4 +1078,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     raise SystemExit(main())

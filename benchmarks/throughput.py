@@ -32,6 +32,7 @@ import numpy as np
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from _util import write_bench_json
+from repro.compile_cache import enable_compile_cache
 from repro.core import hnsw
 from repro.core.backend import SearchParams
 from repro.core.index import LSMVecIndex, brute_force_knn, recall_at_k
@@ -232,4 +233,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     raise SystemExit(main())

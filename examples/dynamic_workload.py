@@ -25,6 +25,7 @@ sys.path.insert(0, "src")
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import DISK, HNSWConfig, LSMVecIndex
 from repro.core.distributed import ShardedBackend
 from repro.core.index import brute_force_knn, recall_at_k
@@ -104,6 +105,7 @@ def main(n_base=1024, dim=48, n_batches=5, n_shards=1):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     shards = 1
     if "--shards" in sys.argv:
         shards = int(sys.argv[sys.argv.index("--shards") + 1])

@@ -10,6 +10,7 @@ sys.path.insert(0, "src")
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import DISK, HNSWConfig, LSMVecIndex, SearchParams  # noqa: F401
 from repro.core.index import brute_force_knn, recall_at_k
 from repro.data.synth import make_clustered_vectors
@@ -65,4 +66,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

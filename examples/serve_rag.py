@@ -29,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import configs
+from repro.compile_cache import enable_compile_cache
 from repro.core import DISK, HNSWConfig, LSMVecIndex
 from repro.models import transformer as T
 from repro.serve import MaintenancePolicy, ServeConfig, ServeEngine
@@ -135,4 +136,5 @@ def main(n_docs=512, doc_len=24, n_requests=8, gen_len=12):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

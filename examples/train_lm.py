@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import configs
+from repro.compile_cache import enable_compile_cache
 from repro.data.synth import token_pipeline
 from repro.ft import FailureInjector, RestartPolicy, run_with_restarts
 from repro.launch import steps as step_lib
@@ -65,6 +66,7 @@ def main(num_steps=200, arch="musicgen-large", ckpt_dir="/tmp/train_lm_ck"):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--arch", default="musicgen-large")

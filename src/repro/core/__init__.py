@@ -26,11 +26,11 @@ from repro.core.backend import (
 )
 from repro.core.hnsw import HNSWConfig, HNSWState
 from repro.core.index import LSMVecIndex, brute_force_knn, recall_at_k
-from repro.core.iostats import DISK, CostModel, IOStats, tpu_hbm_model
+from repro.core.iostats import DISK, CostModel, IOStats
 
 __all__ = [
     "HNSWConfig", "HNSWState", "LSMVecIndex", "brute_force_knn",
-    "recall_at_k", "IOStats", "CostModel", "DISK", "tpu_hbm_model",
+    "recall_at_k", "IOStats", "CostModel", "DISK",
     "VectorBackend", "BackendStats", "ShardStats", "SearchResult",
     "UpdateResult", "SearchParams", "SearchHandle", "MaintenanceReport",
 ]

@@ -20,6 +20,7 @@ from __future__ import annotations
 import json
 import os
 import shutil
+from concurrent.futures import ThreadPoolExecutor
 from typing import Optional, Sequence, Tuple
 
 import jax
@@ -137,6 +138,20 @@ class ShardedDispatch:
         return merge_topk(gids, dists, self._k)
 
 
+def _each_shard(fn, items) -> list:
+    """`[fn(x) for x in items]`, one thread per item.  Shards are
+    independent, and a shard's first call of a program compiles it for
+    that shard's own device: run one after another, a four-chip host
+    pays every compile four times over; concurrently, the compiles and
+    each shard's host work overlap (JAX releases the GIL while it
+    compiles and while devices run)."""
+    items = list(items)
+    if len(items) <= 1:
+        return [fn(x) for x in items]
+    with ThreadPoolExecutor(len(items)) as pool:
+        return list(pool.map(fn, items))
+
+
 class ShardedBackend:
     """P independent LSM-VEC shards behind one `VectorBackend` surface.
 
@@ -211,18 +226,18 @@ class ShardedBackend:
         vectors = np.asarray(vectors, np.float32)
         self.seed = seed
         asg = np.asarray(shard_of_seq(np.arange(n), self.n_shards))
-        shards = []
-        for s in range(self.n_shards):
+
+        def build_shard(s: int) -> LSMVecIndex:
             rows = np.flatnonzero(asg == s)
             if len(rows) == 0:
-                shards.append(self._empty_shard(s))
-                continue
-            st = hnsw.bulk_build(self.cfg, jnp.asarray(vectors[rows]),
-                                 jax.random.key(seed + s))
-            shards.append(LSMVecIndex(
-                self.cfg, seed=seed + s,
-                state=jax.device_put(st, self.devices[s])))
-        self._shards = shards
+                return self._empty_shard(s)
+            with jax.default_device(self.devices[s]):
+                st = hnsw.bulk_build(self.cfg, jnp.asarray(vectors[rows]),
+                                     jax.random.key(seed + s))
+            return LSMVecIndex(self.cfg, seed=seed + s,
+                               state=jax.device_put(st, self.devices[s]))
+
+        self._shards = _each_shard(build_shard, range(self.n_shards))
         local = np.zeros(n, np.int64)
         for s in range(self.n_shards):
             rows = np.flatnonzero(asg == s)
@@ -285,11 +300,13 @@ class ShardedBackend:
             np.arange(self._n_routed, self._n_routed + n), self.n_shards))
         self._n_routed += n
         gids = np.full(n, -1, np.int64)
-        for s in range(self.n_shards):
-            rows = np.flatnonzero(asg == s)
-            if len(rows) == 0:
-                continue
-            res = self.shards[s].insert_batch(xs[rows], pad_to=pad_to)
+        routed = [(s, np.flatnonzero(asg == s)) for s in range(self.n_shards)]
+        routed = [(s, rows) for s, rows in routed if len(rows)]
+        results = _each_shard(
+            lambda sr: self.shards[sr[0]].insert_batch(xs[sr[1]],
+                                                       pad_to=pad_to),
+            routed)
+        for (s, rows), res in zip(routed, results):
             gids[rows] = np.asarray(res.ids, np.int64) \
                 + np.int64(s) * self.cfg.cap
         # allocation order = submission order (ids are assigned in the
@@ -308,11 +325,10 @@ class ShardedBackend:
             return UpdateResult(ids=ids, n_applied=0)
         shard, local = self._split(ids)
         routable = (shard >= 0) & (shard < self.n_shards)
-        for s in range(self.n_shards):
-            sub = local[shard == s]
-            if len(sub):
-                self.shards[s].delete_batch(sub.astype(np.int32),
-                                            pad_to=pad_to)
+        routed = [(s, local[shard == s]) for s in range(self.n_shards)]
+        _each_shard(lambda sr: self.shards[sr[0]].delete_batch(
+            sr[1].astype(np.int32), pad_to=pad_to),
+            [(s, sub) for s, sub in routed if len(sub)])
         return UpdateResult(ids=ids, n_applied=int(routable.sum()))
 
     def maintain(self, op: str, **params) -> MaintenanceReport:
@@ -344,15 +360,13 @@ class ShardedBackend:
 
     def begin_maintain(self, op: str, **params) -> bool:
         """Start overlapped consolidation on every shard whose own
-        tombstone-ratio trigger passes (each repair runs on that
-        shard's device, concurrent with fan-out queries).  True iff at
-        least one shard started."""
+        tombstone-ratio trigger passes (each repair runs on the shard's
+        spare device — the next shard's home — concurrent with fan-out
+        queries).  True iff at least one shard started."""
         if op != "consolidate":
             return False
-        started = False
-        for sh in self.shards:
-            started |= sh.begin_maintain(op, **params)
-        return started
+        return any(_each_shard(lambda sh: sh.begin_maintain(op, **params),
+                               self.shards))
 
     def poll_maintain(self, *, block: bool = False
                       ) -> Optional[MaintenanceReport]:

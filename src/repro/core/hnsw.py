@@ -14,6 +14,7 @@ builds (an exact-kNN bottom graph, the offline analogue).
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Tuple
 
 import jax
@@ -735,7 +736,7 @@ def insert_batch(cfg: HNSWConfig, state: HNSWState, xs: jax.Array,
     # already staged in the overlay when i's backlink pass reads them —
     # the same "link to already-placed nodes" rule sequential insert has.
     bb = (xnorms[:, None] ** 2 + xnorms[None, :] ** 2
-          - 2.0 * (xs @ xs.T))
+          - 2.0 * jnp.matmul(xs, xs.T, precision=jax.lax.Precision.HIGHEST))
     # masked (padding) items are never candidates; valid items form a
     # prefix, so the j < i triangle only ever pairs valid with valid
     bb = jnp.where(jnp.tril(jnp.ones((n, n), jnp.bool_), k=-1)
@@ -1168,7 +1169,8 @@ def _diversity_block(vectors: jax.Array, cand: jax.Array, d: jax.Array,
     cv = vectors[jnp.maximum(ids_s, 0)]                   # [b, C, dim]
     n2 = jnp.sum(cv * cv, axis=-1)
     pair = n2[:, :, None] + n2[:, None, :] \
-        - 2.0 * jnp.einsum("bcd,bed->bce", cv, cv)
+        - 2.0 * jnp.einsum("bcd,bed->bce", cv, cv,
+                           precision=jax.lax.Precision.HIGHEST)
     valid = jnp.isfinite(d_s) & (ids_s >= 0)
 
     def body(i, kept):
@@ -1196,17 +1198,23 @@ def _consolidate_rows(vectors: jax.Array, adj: jax.Array, tomb: jax.Array,
     edges and strips the long-range portals the beam navigates by.
 
     `adj` is a dense view int32[cap, W]; `owner` masks which rows may be
-    rewritten, `member` which ids are valid targets.  Processed in
-    `block`-node chunks under `lax.map` so the [block, W + W*W, dim]
-    distance gather never materializes at full cap.  Returns
+    rewritten, `member` which ids are valid targets.  Only the rows that
+    change are repaired: their ids are packed to the front of one
+    cap-long list and taken `block` at a time, so the [block, W + W*W,
+    dim] distance gather never materializes at full cap and the work
+    scales with the rows that touch a tombstone, not with cap.  Returns
     (new_adj, changed, n_dist) where rows with no tombstoned neighbor
     come back untouched.
     """
     cap = adj.shape[0]
-    nblk = -(-cap // block)
-    ids = jnp.arange(nblk * block, dtype=jnp.int32).reshape(nblk, block)
+    changed = owner & jnp.any((adj >= 0) & tomb[jnp.maximum(adj, 0)],
+                              axis=1)
+    n_changed = jnp.sum(changed).astype(jnp.int32)
+    order = jnp.nonzero(changed, size=cap + block, fill_value=cap)[0]
 
-    def repair(blk):
+    def repair(i, carry):
+        new_adj, n_dist = carry
+        blk = jax.lax.dynamic_slice(order, (i * block,), (block,))
         in_range = blk < cap
         safe_blk = jnp.minimum(blk, cap - 1)
         r = adj[safe_blk]                                    # [b, W]
@@ -1225,15 +1233,15 @@ def _consolidate_rows(vectors: jax.Array, adj: jax.Array, tomb: jax.Array,
         masked = jnp.where(bad, -1, cand)
         d = jax.vmap(_dedup_to_inf)(masked, d)
         new_r = _diversity_block(vectors, cand, d, W)
-        changed = in_range & owner[safe_blk] & jnp.any(parent_tomb, axis=1)
-        n_dist = jnp.sum(
-            jnp.where(changed[:, None], jnp.isfinite(d), False))
-        return jnp.where(changed[:, None], new_r, r), changed, n_dist
+        n_dist = n_dist + jnp.sum(
+            jnp.where(in_range[:, None], jnp.isfinite(d), False))
+        # padding entries (id cap) scatter out of bounds and are dropped
+        return new_adj.at[blk].set(new_r, mode="drop"), n_dist
 
-    new_adj, changed, n_dist = jax.lax.map(repair, ids)
-    return (new_adj.reshape(nblk * block, W)[:cap],
-            changed.reshape(-1)[:cap],
-            jnp.sum(n_dist).astype(jnp.int32))
+    new_adj, n_dist = jax.lax.fori_loop(
+        0, (n_changed + block - 1) // block, repair,
+        (adj, jnp.zeros((), jnp.int32)))
+    return new_adj, changed, n_dist
 
 
 def consolidate(cfg: HNSWConfig, state: HNSWState, *,
@@ -1308,33 +1316,86 @@ def consolidate(cfg: HNSWConfig, state: HNSWState, *,
 # bulk construction (initial index build)
 # ---------------------------------------------------------------------------
 
-def _np_diversity_select(cand: "np.ndarray", cand_d: "np.ndarray",
-                         vecs_np, deg: int, alpha: float = 1.0):
-    """Numpy twin of _diversity_topm (keepPruned heuristic)."""
-    import numpy as np
-    order = np.argsort(cand_d)
-    cand, cand_d = cand[order], cand_d[order]
-    cv = vecs_np[cand]
-    diff = cv[:, None, :] - cv[None, :, :]
-    pair = np.einsum("ijk,ijk->ij", diff, diff)
-    kept: list[int] = []
-    kept_idx: list[int] = []
-    for ci in range(len(cand)):
-        if len(kept) >= deg:
-            break
-        if all(alpha * pair[ci, kj] >= cand_d[ci] for kj in kept_idx):
-            kept.append(int(cand[ci]))
-            kept_idx.append(ci)
-    for ci in range(len(cand)):            # keepPruned fill
-        if len(kept) >= deg:
-            break
-        if int(cand[ci]) not in kept:
-            kept.append(int(cand[ci]))
-            kept_idx.append(ci)
-    return kept, [float(cand_d[j]) for j in kept_idx]
+def _bucket(n: int) -> int:
+    """Pad width of the build's candidate blocks: the next power of two,
+    at least 128.  Each distinct width is one compiled program, so
+    loading compiles O(log n) programs instead of one per batch."""
+    return max(128, 1 << max(n - 1, 0).bit_length())
 
 
-def _incremental_graph(vecs_np, member_ids, deg: int, seed: int,
+@functools.partial(jax.jit, static_argnames=("batch", "width", "kk"))
+def _nearest_placed(arrived, start, n_placed, *, batch, width, kk):
+    """The `kk` nearest placed nodes of each row of one arrival chunk.
+
+    `arrived` holds the layer's vectors in arrival order (zero rows past
+    the end), so the placed set is always the prefix `[:n_placed]`: the
+    chunk `arrived[start:start + batch]` is scored against the first
+    `width` rows, columns past `n_placed` are masked to +inf, and the
+    top `kk` come back as (distance, arrival position) pairs — ascending
+    by distance, lowest position first on ties."""
+    chunk = jax.lax.dynamic_slice_in_dim(arrived, start, batch)
+    d = l2_distance(chunk, arrived[:width])
+    d = jnp.where(jnp.arange(width) < n_placed, d, INF)
+    neg, pos = jax.lax.top_k(-d, kk)
+    return -neg, pos
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _link_chunk(rows, vecs, order, start, n_chunk, n_cand, cand_d, cand_p):
+    """Link one arrival chunk into a layer's adjacency `rows` (node ids,
+    int32[n_total, deg], -1 = empty).
+
+    Row b of the chunk is node `order[start + b]` (rows b >= n_chunk are
+    padding).  Its neighbors are diversity-selected (HNSW's keepPruned
+    heuristic) among its first `n_cand` candidates `cand_p` (arrival
+    positions, nearest first): a candidate is kept unless an already
+    kept one is closer to it than it is to the node, and the pruned ones
+    fill what is left of the row.  Then, node by node in arrival order,
+    each neighbor takes a back-edge: its first free slot, or the slot of
+    its edge most redundant w.r.t. the newcomer.
+    """
+    n_total, deg = rows.shape
+    b, c = cand_p.shape
+    nodes = order[start + jnp.arange(b)]
+    live = jnp.arange(b) < n_chunk
+    valid = jnp.broadcast_to(jnp.arange(c) < n_cand, (b, c))
+    cand = order[cand_p]                                    # [b, c]
+    cv = vecs[cand]                                         # [b, c, dim]
+    n2 = jnp.sum(cv * cv, axis=-1)
+    pair = n2[:, :, None] + n2[:, None, :] - 2.0 * jnp.einsum(
+        "bcd,bed->bce", cv, cv, precision=jax.lax.Precision.HIGHEST)
+    spares = pair >= cand_d[:, :, None]     # spares[r, i, j]: j spares i
+
+    def keep(ci, carry):
+        kept, n_kept = carry
+        take = (~jnp.any(kept & ~spares[:, ci, :], axis=1)
+                & (n_kept < deg) & valid[:, ci])
+        return kept.at[:, ci].set(take), n_kept + take
+
+    kept, _ = jax.lax.fori_loop(
+        0, c, keep, (jnp.zeros((b, c), jnp.bool_), jnp.zeros((b,), jnp.int32)))
+    # kept first, then pruned, each nearest first; invalid never
+    rank = jnp.argsort(jnp.where(valid, (~kept).astype(jnp.int32), 2),
+                       axis=1, stable=True)[:, :deg]
+    nbrs = jnp.where(jnp.take_along_axis(valid, rank, axis=1)
+                     & live[:, None],
+                     jnp.take_along_axis(cand, rank, axis=1), -1)
+
+    def link(r, rows):
+        i, nb = nodes[r], nbrs[r]
+        rows = rows.at[jnp.where(live[r], i, n_total)].set(nb, mode="drop")
+        back = rows[jnp.maximum(nb, 0)]                     # [deg, deg]
+        d_new = jnp.sum((vecs[jnp.maximum(back, 0)] - vecs[i]) ** 2, axis=-1)
+        free = back < 0
+        slot = jnp.where(jnp.any(free, axis=1), jnp.argmax(free, axis=1),
+                         jnp.argmin(d_new, axis=1))
+        return rows.at[jnp.where(nb >= 0, nb, n_total), slot].set(
+            i, mode="drop")
+
+    return jax.lax.fori_loop(0, b, link, rows)
+
+
+def _incremental_graph(vecs, member_ids, deg: int, seed: int,
                        batch: int = 64):
     """Batched random-order incremental construction of one layer.
 
@@ -1343,19 +1404,19 @@ def _incremental_graph(vecs_np, member_ids, deg: int, seed: int,
     evict the placed node's most redundant edge.  Early arrivals keep
     long-range links, which is exactly how incremental HNSW/NSW layers
     become navigable — an exact kNN graph would fall apart into per-cluster
-    islands.  Host-side numpy; the per-batch distance block uses the shared
-    kernel wrapper.
+    islands.  Runs on the device: the layer's vectors are gathered into
+    arrival order once, each batch's exact nearest candidates come from
+    `_nearest_placed` at a bucketed width, and `_link_chunk` links the
+    batch into the adjacency, which stays on the device until the layer
+    is done — the host only dispatches.  `vecs` is f32[n_total, dim].
     """
     import numpy as np
-    n_total = vecs_np.shape[0]
-    rows = np.full((n_total, deg), -1, np.int32)
-    rowd = np.full((n_total, deg), np.inf, np.float32)
+    n_total = vecs.shape[0]
     ids = np.asarray(member_ids)
     if ids.size == 0:
-        return rows
+        return np.full((n_total, deg), -1, np.int32)
     rng = np.random.default_rng(seed)
     order = ids[rng.permutation(ids.size)]
-    placed = [int(order[0])]
     # geometric batch ramp: early nodes (the long-range hubs) must connect
     # densely to each other, not just to the seed
     bounds = [1]
@@ -1363,41 +1424,29 @@ def _incremental_graph(vecs_np, member_ids, deg: int, seed: int,
     while bounds[-1] < order.size:
         bounds.append(min(bounds[-1] + step, order.size))
         step = min(batch, step * 2)
+    # candidate pool for diversity.  Very small builds (tiny shards,
+    # sparse upper layers) see the *complete* placed set: with only a
+    # few dozen nodes the 2*deg nearest candidates all sit inside one
+    # tight cluster and diversity selection can strand other clusters
+    # entirely (the small-shard navigability loss).
+    small = ids.size <= max(128, 4 * deg)
+    # every layer pads to the full build's row count, so layers share
+    # their programs: one per (bucketed width, cut)
+    n_rows = _bucket(n_total) + batch
+    pos = np.zeros(n_rows, np.int32)
+    pos[:order.size] = order
+    pos = jnp.asarray(pos)
+    arrived = jnp.where((jnp.arange(n_rows) < order.size)[:, None],
+                        vecs[pos], 0.0)
+    rows = jnp.full((n_total, deg), -1, jnp.int32)
     for s, e in zip(bounds[:-1], bounds[1:]):
-        chunk = order[s:e]
-        pv = jnp.asarray(vecs_np[np.asarray(placed)])
-        d_blk = np.asarray(l2_distance(jnp.asarray(vecs_np[chunk]), pv))
-        # candidate pool for diversity.  Very small builds (tiny shards,
-        # sparse upper layers) see the *complete* placed set: with only a
-        # few dozen nodes the 2*deg nearest candidates all sit inside one
-        # tight cluster and diversity selection can strand other clusters
-        # entirely (the small-shard navigability loss).
-        kk = len(placed) if ids.size <= max(128, 4 * deg) \
-            else min(2 * deg, len(placed))
-        top = np.argpartition(d_blk, kk - 1, axis=1)[:, :kk] \
-            if kk < len(placed) else \
-            np.broadcast_to(np.arange(len(placed)), (len(chunk),
-                                                     len(placed)))
-        placed_arr = np.asarray(placed)
-        for bi, i in enumerate(chunk):
-            cand = placed_arr[top[bi]]
-            nb, nd = _np_diversity_select(cand, d_blk[bi, top[bi]],
-                                          vecs_np, deg)
-            rows[i, : len(nb)] = nb
-            rowd[i, : len(nd)] = nd
-            for p_, d_ in zip(nb, nd):
-                free = np.flatnonzero(rows[p_] < 0)
-                if free.size:
-                    j = int(free[0])
-                else:
-                    # evict the edge most redundant w.r.t. the newcomer
-                    nbr_vecs = vecs_np[rows[p_]]
-                    d_to_new = ((nbr_vecs - vecs_np[i]) ** 2).sum(1)
-                    j = int(np.argmin(d_to_new))
-                rows[p_, j] = i
-                rowd[p_, j] = d_
-            placed.append(int(i))
-    return rows
+        width = _bucket(s)
+        cand_d, cand_p = _nearest_placed(
+            arrived, s, s, batch=batch, width=width,
+            kk=width if small else min(2 * deg, width))
+        rows = _link_chunk(rows, vecs, pos, s, e - s,
+                           s if small else min(2 * deg, s), cand_d, cand_p)
+    return np.array(rows)     # writable: reachability repair edits it
 
 
 def _repair_reachability(rows, vecs_np, member_ids, entry: int, deg: int):
@@ -1405,16 +1454,22 @@ def _repair_reachability(rows, vecs_np, member_ids, entry: int, deg: int):
 
     Diversity selection on clustered data can leave whole clusters as
     graph islands (no inbound path from the entry chain), which beam
-    search then never finds no matter the ef.  Repair: BFS from the
-    entry; while any member is unreachable, bridge the globally closest
-    (reachable, unreachable) pair with a bidirectional edge — each
-    bridge absorbs that island's entire component.  Bridge edges are
-    *protected*: a full row evicts its unprotected slot most redundant
-    w.r.t. the new neighbor (the `_backlink_rows` rule), never an
-    earlier bridge — two islands sharing one anchor would otherwise
-    evict each other's bridge forever.  Anchors with no evictable slot
-    are skipped, and the loop is bounded by the member count, so repair
-    always terminates.
+    search then never finds no matter the ef.  Repair: while any member
+    is unreachable, bridge the globally closest (reachable, unreachable)
+    pair with a bidirectional edge — each bridge absorbs everything
+    reachable from the bridged node.  Bridge edges are *protected*: a
+    full row evicts its unprotected slot most redundant w.r.t. the new
+    neighbor (the `_backlink_rows` rule), never an earlier bridge — two
+    islands sharing one anchor would otherwise evict each other's bridge
+    forever.  Anchors with no evictable slot are skipped.
+
+    Each unreachable node's nearest anchor is kept and only updated
+    against the anchors a bridge adds (and recomputed when its anchor
+    runs out of evictable slots), so a layer with many islands costs one
+    blocked nearest-anchor pass plus small updates, not a full pass per
+    bridge.  An eviction can cut a path found earlier, so a full BFS
+    from the entry re-checks the layer after every round; the rounds
+    are bounded by the member count, so repair always terminates.
     """
     import numpy as np
     members = np.asarray(member_ids)
@@ -1423,18 +1478,40 @@ def _repair_reachability(rows, vecs_np, member_ids, entry: int, deg: int):
     in_layer = np.zeros(rows.shape[0], bool)
     in_layer[members] = True
     protected = np.zeros(rows.shape, bool)
+    sq = np.zeros(rows.shape[0], np.float32)
+    sq[members] = (vecs_np[members] ** 2).sum(1)
 
-    def bfs():
-        seen = np.zeros(rows.shape[0], bool)
-        seen[entry] = True
-        frontier = np.asarray([entry])
+    def absorb(start: int, seen):
+        """Mark everything reachable from `start`; returns the new nodes."""
+        seen[start] = True
+        frontier = np.asarray([start])
+        found = [frontier]
         while frontier.size:
             nxt = rows[frontier].ravel()
             nxt = np.unique(nxt[nxt >= 0])
             nxt = nxt[in_layer[nxt] & ~seen[nxt]]
             seen[nxt] = True
+            found.append(nxt)
             frontier = nxt
-        return seen
+        return np.concatenate(found)
+
+    def evictable(nodes):
+        return ((rows[nodes] < 0) | ~protected[nodes]).any(axis=1)
+
+    def nearest(us, anchors):
+        """(squared distance, id) of each node's nearest anchor."""
+        best_d = np.full(us.size, np.inf, np.float32)
+        best_a = np.full(us.size, -1, np.int64)
+        for s in range(0, anchors.size, 8192):
+            blk = anchors[s:s + 8192]
+            d = (sq[us][:, None] + sq[blk][None, :]
+                 - 2.0 * (vecs_np[us] @ vecs_np[blk].T))
+            j = np.argmin(d, axis=1)
+            dj = d[np.arange(us.size), j]
+            better = dj < best_d
+            best_d[better] = dj[better]
+            best_a[better] = blk[j[better]]
+        return best_d, best_a
 
     def add_edge(src: int, dst: int):
         if dst in rows[src]:
@@ -1454,22 +1531,36 @@ def _repair_reachability(rows, vecs_np, member_ids, entry: int, deg: int):
         protected[src, j] = True
 
     for _ in range(members.size):
-        seen = bfs()
+        seen = np.zeros(rows.shape[0], bool)
+        absorb(entry, seen)
         un = members[~seen[members]]
         if un.size == 0:
             break
         reach = members[seen[members]]
-        d = ((vecs_np[un][:, None, :]
-              - vecs_np[reach][None, :, :]) ** 2).sum(-1)
-        # only anchors that can still take a bridge edge
-        evictable = ((rows[reach] < 0) | ~protected[reach]).any(axis=1)
-        if not evictable.any():
-            break
-        d[:, ~evictable] = np.inf
-        bi, bj = np.unravel_index(int(np.argmin(d)), d.shape)
-        u_node, r_node = int(un[bi]), int(reach[bj])
-        add_edge(r_node, u_node)
-        add_edge(u_node, r_node)
+        best_d, best_a = nearest(un, reach[evictable(reach)])
+        while un.size and np.isfinite(best_d).any():
+            j = int(np.argmin(best_d))
+            u_node, r_node = int(un[j]), int(best_a[j])
+            add_edge(r_node, u_node)
+            add_edge(u_node, r_node)
+            new = absorb(u_node, seen)
+            keep = ~seen[un]
+            un, best_d, best_a = un[keep], best_d[keep], best_a[keep]
+            if not un.size:
+                break
+            fresh = new[evictable(new)]
+            if fresh.size:
+                d2, a2 = nearest(un, fresh)
+                better = d2 < best_d
+                best_d[better] = d2[better]
+                best_a[better] = a2[better]
+            stale = (best_a >= 0) & ~evictable(np.maximum(best_a, 0))
+            if stale.any():
+                reach = members[seen[members]]
+                best_d[stale], best_a[stale] = nearest(
+                    un[stale], reach[evictable(reach)])
+        if un.size and not np.isfinite(best_d).any():
+            break           # no anchor can take a bridge
     return rows
 
 
@@ -1503,7 +1594,7 @@ def bulk_build(cfg: HNSWConfig, vectors: jax.Array, key: jax.Array,
 
     # entry = node 0 (forced to the top level above); every layer repairs
     # reachability from it so no cluster is stranded as a graph island
-    bottom = _incremental_graph(vecs_np, np.arange(n), cfg.M, seed=0,
+    bottom = _incremental_graph(vecs, np.arange(n), cfg.M, seed=0,
                                 batch=batch)
     bottom = _repair_reachability(bottom, vecs_np, np.arange(n), 0, cfg.M)
     store = lsm.bulk_load(cfg.lsm_cfg, ids, jnp.asarray(bottom))
@@ -1511,7 +1602,7 @@ def bulk_build(cfg: HNSWConfig, vectors: jax.Array, key: jax.Array,
     upper = jnp.full((cfg.num_upper, cfg.cap, cfg.M_up), -1, jnp.int32)
     for u in range(cfg.num_upper):
         members = np.flatnonzero(lvls_np > u)
-        rows_u = _incremental_graph(vecs_np, members, cfg.M_up, seed=u + 1,
+        rows_u = _incremental_graph(vecs, members, cfg.M_up, seed=u + 1,
                                     batch=batch)
         rows_u = _repair_reachability(rows_u, vecs_np, members, 0, cfg.M_up)
         upper = upper.at[u, :n].set(jnp.asarray(rows_u))

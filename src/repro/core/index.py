@@ -9,6 +9,7 @@ accounting the paper's experiments report.
 from __future__ import annotations
 
 import functools
+from types import SimpleNamespace
 from typing import Optional, Tuple
 
 import jax
@@ -67,6 +68,93 @@ def recall_at_k(found_ids: np.ndarray, true_ids: np.ndarray,
         fb, tb = f[s:s + block], t[s:s + block]
         hits += int((fb[:, :, None] == tb[:, None, :]).any(axis=1).sum())
     return hits / (k * len(t))
+
+
+def jit_programs(cfg: hnsw.HNSWConfig) -> SimpleNamespace:
+    """The jitted device programs of one `LSMVecIndex` config: every
+    entry point an index dispatches, built once per index.  Module-level
+    so that a caller can also lower them against shapes alone (the
+    ahead-of-time TPU compile tests do)."""
+    @functools.partial(jax.jit, donate_argnums=0)
+    def _insert(state, x, key):
+        return hnsw.insert(cfg, state, x, key)
+
+    @functools.partial(jax.jit, donate_argnums=0)
+    def _insert_batch(state, xs, keys, valid):
+        return hnsw.insert_batch(cfg, state, xs, keys, valid=valid)
+
+    @functools.partial(jax.jit, donate_argnums=0)
+    def _delete(state, i):
+        return hnsw.delete(cfg, state, i)
+
+    @functools.partial(jax.jit, donate_argnums=0)
+    def _delete_batch(state, ids):
+        return hnsw.delete_batch(cfg, state, ids)
+
+    @functools.partial(jax.jit, donate_argnums=(0, 4))
+    def _insert_batch_snap(state, xs, keys, valid, snap):
+        state, st, (orows, ovalid) = hnsw.insert_batch(
+            cfg, state, xs, keys, valid=valid, return_overlay=True)
+        snap = jnp.where(ovalid[:cfg.cap, None], orows[:cfg.cap], snap)
+        return state, st, snap
+
+    @functools.partial(jax.jit, donate_argnums=0)
+    def _consolidate(state):
+        return hnsw.consolidate(cfg, state)
+
+    # non-donated: the live state keeps serving queries while the
+    # repair computes against it (double-buffer, DESIGN.md §13)
+    @jax.jit
+    def _consolidate_bg(state):
+        return hnsw.consolidate(cfg, state)
+
+    # `record_heat` is static: False drops the scatter-add (and, on
+    # the fused path, the loop's heat carries) from the trace —
+    # callers that never apply heat don't pay for recording it
+    @functools.partial(jax.jit, static_argnames=("rho", "use_filter",
+                                                 "ef", "n_expand",
+                                                 "record_heat"))
+    def _search(state, qs, rho, use_filter, ef, n_expand,
+                record_heat=True):
+        res = hnsw.search_batch(cfg, state, qs, rho=rho,
+                                use_filter=use_filter, ef=ef,
+                                n_expand=n_expand)
+        heat_delta = _heat_delta(state, res) if record_heat \
+            else jnp.zeros_like(state.heat)
+        return res, heat_delta
+
+    @functools.partial(jax.jit, static_argnames=("rho", "use_filter",
+                                                 "ef", "n_expand",
+                                                 "record_heat"))
+    def _search_snap(state, qs, valid, snap, rho, use_filter, ef,
+                     n_expand, record_heat=True):
+        res = hnsw.search_batch(cfg, state, qs, rho=rho,
+                                use_filter=use_filter, ef=ef,
+                                n_expand=n_expand, snapshot=snap,
+                                active=valid,
+                                record_heat=record_heat)
+        heat_delta = _heat_delta(state, res) if record_heat \
+            else jnp.zeros_like(state.heat)
+        return res, heat_delta
+
+    @jax.jit
+    def _resolve(state):
+        return lsm.snapshot_rows(cfg.lsm_cfg, state.store, cfg.cap)
+
+    def _heat_delta(state, res):
+        nodes = res.heat_nodes.reshape(-1)
+        mask = res.heat_mask.reshape(-1, cfg.M)
+        safe = jnp.maximum(nodes, 0)
+        contrib = jnp.where((nodes >= 0)[:, None], mask, False)
+        return jnp.zeros_like(state.heat).at[safe].add(
+            contrib.astype(jnp.int32))
+
+    return SimpleNamespace(
+        insert=_insert, insert_batch=_insert_batch,
+        insert_batch_snap=_insert_batch_snap, delete=_delete,
+        delete_batch=_delete_batch, consolidate=_consolidate,
+        consolidate_bg=_consolidate_bg, search=_search,
+        search_snap=_search_snap, resolve=_resolve)
 
 
 class DispatchedSearch:
@@ -140,92 +228,17 @@ class LSMVecIndex:
         self._pending_repair = None
         self._done_report: Optional[MaintenanceReport] = None
 
-        cfg_ = self.cfg
-
-        @functools.partial(jax.jit, donate_argnums=0)
-        def _insert(state, x, key):
-            return hnsw.insert(cfg_, state, x, key)
-
-        @functools.partial(jax.jit, donate_argnums=0)
-        def _insert_batch(state, xs, keys, valid):
-            return hnsw.insert_batch(cfg_, state, xs, keys, valid=valid)
-
-        @functools.partial(jax.jit, donate_argnums=0)
-        def _delete(state, i):
-            return hnsw.delete(cfg_, state, i)
-
-        @functools.partial(jax.jit, donate_argnums=0)
-        def _delete_batch(state, ids):
-            return hnsw.delete_batch(cfg_, state, ids)
-
-        @functools.partial(jax.jit, donate_argnums=(0, 4))
-        def _insert_batch_snap(state, xs, keys, valid, snap):
-            state, st, (orows, ovalid) = hnsw.insert_batch(
-                cfg_, state, xs, keys, valid=valid, return_overlay=True)
-            snap = jnp.where(ovalid[:cfg_.cap, None], orows[:cfg_.cap], snap)
-            return state, st, snap
-
-        @functools.partial(jax.jit, donate_argnums=0)
-        def _consolidate(state):
-            return hnsw.consolidate(cfg_, state)
-
-        # non-donated: the live state keeps serving queries while the
-        # repair computes against it (double-buffer, DESIGN.md §13)
-        @jax.jit
-        def _consolidate_bg(state):
-            return hnsw.consolidate(cfg_, state)
-
-        # `record_heat` is static: False drops the scatter-add (and, on
-        # the fused path, the loop's heat carries) from the trace —
-        # callers that never apply heat don't pay for recording it
-        @functools.partial(jax.jit, static_argnames=("rho", "use_filter",
-                                                     "ef", "n_expand",
-                                                     "record_heat"))
-        def _search(state, qs, rho, use_filter, ef, n_expand,
-                    record_heat=True):
-            res = hnsw.search_batch(cfg_, state, qs, rho=rho,
-                                    use_filter=use_filter, ef=ef,
-                                    n_expand=n_expand)
-            heat_delta = _heat_delta(state, res) if record_heat \
-                else jnp.zeros_like(state.heat)
-            return res, heat_delta
-
-        @functools.partial(jax.jit, static_argnames=("rho", "use_filter",
-                                                     "ef", "n_expand",
-                                                     "record_heat"))
-        def _search_snap(state, qs, valid, snap, rho, use_filter, ef,
-                         n_expand, record_heat=True):
-            res = hnsw.search_batch(cfg_, state, qs, rho=rho,
-                                    use_filter=use_filter, ef=ef,
-                                    n_expand=n_expand, snapshot=snap,
-                                    active=valid,
-                                    record_heat=record_heat)
-            heat_delta = _heat_delta(state, res) if record_heat \
-                else jnp.zeros_like(state.heat)
-            return res, heat_delta
-
-        @jax.jit
-        def _resolve(state):
-            return lsm.snapshot_rows(cfg_.lsm_cfg, state.store, cfg_.cap)
-
-        def _heat_delta(state, res):
-            nodes = res.heat_nodes.reshape(-1)
-            mask = res.heat_mask.reshape(-1, cfg_.M)
-            safe = jnp.maximum(nodes, 0)
-            contrib = jnp.where((nodes >= 0)[:, None], mask, False)
-            return jnp.zeros_like(state.heat).at[safe].add(
-                contrib.astype(jnp.int32))
-
-        self._insert_fn = _insert
-        self._insert_batch_fn = _insert_batch
-        self._insert_batch_snap_fn = _insert_batch_snap
-        self._delete_fn = _delete
-        self._delete_batch_fn = _delete_batch
-        self._consolidate_fn = _consolidate
-        self._consolidate_bg_fn = _consolidate_bg
-        self._search_fn = _search
-        self._search_snap_fn = _search_snap
-        self._resolve_fn = _resolve
+        p = jit_programs(cfg)
+        self._insert_fn = p.insert
+        self._insert_batch_fn = p.insert_batch
+        self._insert_batch_snap_fn = p.insert_batch_snap
+        self._delete_fn = p.delete
+        self._delete_batch_fn = p.delete_batch
+        self._consolidate_fn = p.consolidate
+        self._consolidate_bg_fn = p.consolidate_bg
+        self._search_fn = p.search
+        self._search_snap_fn = p.search_snap
+        self._resolve_fn = p.resolve
 
     # -- construction ---------------------------------------------------------
 
@@ -502,10 +515,7 @@ class LSMVecIndex:
 
     def _home_device(self):
         """The device the live state is committed to."""
-        try:
-            return next(iter(self.state.count.devices()))
-        except AttributeError:      # pragma: no cover - old jax
-            return jax.local_devices()[0]
+        return next(iter(self.state.count.devices()))
 
     def _spare_device(self):
         """A local device other than the home device, if one exists —

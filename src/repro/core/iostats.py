@@ -6,10 +6,8 @@ each) and how many full vectors were fetched from the slow tier (`n_vec`,
 pays `t_v` each).  `n_filtered` counts neighbors the SimHash filter
 skipped — the saving Delta of Eq. 9.
 
-Two cost models ship by default:
- - `DISK`   — the paper's hardware (NVMe 4 KB random reads).
- - `TPU_HBM`— the TPU mapping (row bytes / HBM bandwidth) used by the
-   roofline analysis.
+The cost model that ships is `DISK`, the paper's hardware (NVMe 4 KB
+random reads).  Time on the TPU is measured on the chip, not modelled.
 """
 
 from __future__ import annotations
@@ -41,11 +39,6 @@ class CostModel(NamedTuple):
 
 # NVMe random 4KB read ~= 100 us; neighbor lists are similar-size reads.
 DISK = CostModel(t_n=100e-6, t_v=100e-6)
-
-
-def tpu_hbm_model(dim: int, row_width: int, bw_bytes: float = 819e9) -> CostModel:
-    """Cost model for the TPU mapping: bytes moved / HBM bandwidth."""
-    return CostModel(t_n=row_width * 4 / bw_bytes, t_v=dim * 4 / bw_bytes)
 
 
 def search_cost(stats: IOStats, model: CostModel) -> jnp.ndarray:

@@ -133,11 +133,11 @@ def beam_search(
     # entry vector was fetched to compute entry_dist (not on masked lanes)
     stats = stats._replace(n_vec=stats.n_vec + entry_n_vec)
 
-    # frontier threshold: stop expanding once every candidate within the
-    # 3k-th best has been visited.  k-exact termination prunes too hard on
-    # delete-damaged graphs (measured: recall 0.96 -> 0.46 post-delete);
-    # 3k keeps recall while cutting ~40% of the tail expansions.
-    fidx = min(ef, 3 * k) - 1
+    # frontier threshold (classic HNSW): stop expanding once every
+    # candidate within the ef-th best has been visited, so `ef` is the
+    # recall knob.  A tighter cut (the k-th or 3k-th best) ignores every
+    # ef past it and caps recall on dense clusters.
+    fidx = ef - 1
 
     def cond(carry):
         it, beam_ids, beam_d, expanded, _, stats, *_ = carry

@@ -119,7 +119,7 @@ def _make_beam_kernel(*, B, M, ef, k, cap, dpad, W, iter_cap, max_iters,
                       m_bits, eps, rho, use_filter, tier, lazy,
                       record_heat):
     BM = B * M
-    fidx = min(ef, 3 * k) - 1
+    fidx = ef - 1   # classic HNSW frontier cut, as in traversal
     import math
     if use_filter:
         slack = math.sqrt(m_bits * math.log(1.0 / eps) / 2.0)

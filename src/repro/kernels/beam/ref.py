@@ -124,7 +124,7 @@ def _beam_one(q, entry, entry_dist, code_q, q_norm, act,
         heat_nodes = jnp.zeros((), jnp.int32)
         heat_mask = jnp.zeros((), jnp.bool_)
 
-    fidx = min(ef, 3 * k) - 1
+    fidx = ef - 1   # classic HNSW frontier cut, as in traversal
 
     def cond(carry):
         it, beam_ids, beam_d, expanded, _, _, _, _, hops, *_ = carry

@@ -8,7 +8,9 @@ Tiling: grid (Q/bq, N/bn).  Each program holds a (bq, d) query tile and a
 (bn, d) candidate tile in VMEM and emits a (bq, bn) distance tile.  bq/bn
 default to 128 (MXU-aligned); d is kept whole per tile — embedding dims in
 this system are 128-1024 so a full row fits VMEM comfortably
-(128 x 1024 x 4 B = 512 KB per operand tile).
+(128 x 1024 x 4 B = 512 KB per operand tile).  The cross term runs at
+full f32 precision: the decomposition subtracts nearly equal terms, so a
+single bf16 MXU pass would reorder near neighbors.
 """
 
 from __future__ import annotations
@@ -27,6 +29,7 @@ def _l2_kernel(q_ref, c_ref, o_ref):
     c2 = jnp.sum(c * c, axis=1, keepdims=True).T            # [1, bn]
     cross = jax.lax.dot_general(
         q, c, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)                 # [bq, bn] on MXU
     o_ref[...] = jnp.maximum(q2 + c2 - 2.0 * cross, 0.0)
 

@@ -296,3 +296,26 @@ def test_mixed_batch_and_single_updates():
     q = make_data(4, seed=16)
     d = idx.search(q, k=5).dists
     assert np.isfinite(d).all()
+
+
+def test_insert_batch_every_insert_reachable_from_pre_batch_graph():
+    """Each acked insert of a batch is reachable from the entry over the
+    bottom layer and has an in-link from a node that existed before the
+    batch — it does not hang off its batch siblings alone."""
+    base = make_data(768, seed=11)
+    idx = LSMVecIndex.build(CFG, base)
+    ids = idx.insert_batch(make_data(128, seed=12)).ids
+    live, rows = lsm.resolve_all(CFG.lsm_cfg, idx.state.store, CFG.cap)
+    rows = np.asarray(jnp.where(live[:, None] > 0, rows, -1))
+    n = idx._count
+    old = rows[:len(base)]
+    assert np.isin(ids, old[old >= 0]).all()
+    seen = np.zeros(n, bool)
+    frontier = np.asarray([int(idx.state.entry)])
+    seen[frontier] = True
+    while len(frontier):
+        nxt = rows[frontier].ravel()
+        nxt = np.unique(nxt[(nxt >= 0) & (nxt < n)])
+        frontier = nxt[~seen[nxt]]
+        seen[frontier] = True
+    assert seen[ids].all()

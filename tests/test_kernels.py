@@ -127,6 +127,44 @@ def test_gather_l2_q8_pad_lane_roundtrip_dim65_exact():
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
 
+@pytest.mark.parametrize("dim", [65, 128])
+@pytest.mark.parametrize("n", [48, 1000, 1001])
+def test_gather_l2_row_dma_kernels_bit_identical(dim, n):
+    """The row-DMA kernels (each candidate's row fetched by its own copy;
+    int8 rows in 8-row groups, the id's row kept) equal the references
+    bit for bit, tables whose row count is not a group multiple and -1
+    ids included.  Integer-valued inputs and power-of-two scales keep
+    every sum exact in f32."""
+    kq, kt, kt8, ks, ki = jax.random.split(jax.random.key(dim * n), 5)
+    queries = jax.random.randint(kq, (4, dim), -8, 8).astype(jnp.float32)
+    table = jax.random.randint(kt, (n, dim), -8, 8).astype(jnp.float32)
+    qtable = jax.random.randint(kt8, (n, dim), -127, 128).astype(jnp.int8)
+    scales = 2.0 ** jax.random.randint(ks, (n,), -3, 3).astype(jnp.float32)
+    ids = jax.random.randint(ki, (4, 24), -1, n, jnp.int32)
+    ids = ids.at[:, 0].set(n - 1).at[:, 1].set(-1)   # last row, a sentinel
+    out = gather_l2(queries, table, ids, use_pallas=True, interpret=True)
+    np.testing.assert_array_equal(np.asarray(out),
+                                  np.asarray(gather_l2_ref(queries, table,
+                                                           ids)))
+    out8 = gather_l2_q8(queries, qtable, scales, ids, use_pallas=True,
+                        interpret=True)
+    np.testing.assert_array_equal(
+        np.asarray(out8),
+        np.asarray(gather_l2_q8_ref(queries, qtable, scales, ids)))
+
+
+def test_gather_l2_kernel_under_vmap_matches_ref():
+    """The search path calls the kernel per query under vmap (one query,
+    K candidates); batching must keep every query's ids its own."""
+    q = jax.random.normal(jax.random.key(1), (5, 128))
+    table = jax.random.normal(jax.random.key(2), (100, 128))
+    ids = jax.random.randint(jax.random.key(3), (5, 16), -1, 100, jnp.int32)
+    per_query = jax.vmap(lambda qq, ii: gather_l2(
+        qq[None], table, ii[None], use_pallas=True, interpret=True)[0])
+    np.testing.assert_array_equal(np.asarray(per_query(q, ids)),
+                                  np.asarray(gather_l2_ref(q, table, ids)))
+
+
 def test_gather_l2_dim_mismatch_guard():
     queries = jnp.zeros((2, 65))
     table = jnp.zeros((8, 64))
