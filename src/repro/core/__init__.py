@@ -16,6 +16,7 @@
 
 from repro.core.backend import (
     BackendStats,
+    BeamCounters,
     MaintenanceReport,
     SearchHandle,
     SearchParams,
@@ -33,4 +34,5 @@ __all__ = [
     "recall_at_k", "IOStats", "CostModel", "DISK",
     "VectorBackend", "BackendStats", "ShardStats", "SearchResult",
     "UpdateResult", "SearchParams", "SearchHandle", "MaintenanceReport",
+    "BeamCounters",
 ]

@@ -42,15 +42,33 @@ import numpy as np
 
 
 @dataclass(frozen=True)
+class BeamCounters:
+    """What one search batch's beam loop did on the device.
+
+    `trips`: trips the batch's loop ran (it runs until its slowest lane
+    stops); `hops`: nodes expanded, summed over the batch's queries;
+    `slots`: expansions the loop had room for — trips x lanes dispatched
+    (padded lanes included) x nodes popped per trip.  `hops / slots` is
+    the share of the loop's lanes that did work.
+    """
+
+    trips: int
+    hops: int
+    slots: int
+
+
+@dataclass(frozen=True)
 class SearchResult:
     """Batched ANN search result in the backend's internal id space.
 
     `ids` int [B, k] (-1 pads under-full rows), `dists` f32 [B, k]
-    (squared L2, +inf on pads).
+    (squared L2, +inf on pads).  `beam`: the batch's beam-loop counters,
+    where the backend reports them.
     """
 
     ids: np.ndarray
     dists: np.ndarray
+    beam: Optional[BeamCounters] = None
 
 
 @dataclass(frozen=True)

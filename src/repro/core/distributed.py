@@ -17,6 +17,7 @@ Two shard-local engines:
 
 from __future__ import annotations
 
+import dataclasses
 import json
 import os
 import shutil
@@ -32,6 +33,7 @@ from repro.checkpoint import ckpt
 from repro.core import hnsw
 from repro.core.backend import (
     BackendStats,
+    BeamCounters,
     MaintenanceReport,
     SearchParams,
     SearchResult,
@@ -128,14 +130,21 @@ class ShardedDispatch:
         return all(h.is_ready() for h in self._handles)
 
     def collect(self) -> SearchResult:
-        gids, dists = [], []
-        for s, h in enumerate(self._handles):
-            res = h.collect()
-            base = np.int64(s) * self._cap
-            gids.append(np.where(res.ids >= 0,
-                                 res.ids.astype(np.int64) + base, -1))
-            dists.append(res.dists)
-        return merge_topk(gids, dists, self._k)
+        with jax.profiler.TraceAnnotation("lsmvec.search.collect"):
+            gids, dists, beams = [], [], []
+            for s, h in enumerate(self._handles):
+                res = h.collect()
+                base = np.int64(s) * self._cap
+                gids.append(np.where(res.ids >= 0,
+                                     res.ids.astype(np.int64) + base, -1))
+                dists.append(res.dists)
+                beams.append(res.beam)
+            # the shards run side by side: the slowest shard's trips
+            beam = BeamCounters(trips=max(b.trips for b in beams),
+                                hops=sum(b.hops for b in beams),
+                                slots=sum(b.slots for b in beams))
+            return dataclasses.replace(merge_topk(gids, dists, self._k),
+                                       beam=beam)
 
 
 def _each_shard(fn, items) -> list:
@@ -276,10 +285,11 @@ class ShardedBackend:
         max shard latency instead of the sum.  All per-query knobs
         forward to the shards unchanged, so the merged result at
         shards=1 is bit-identical to the single-device index."""
-        k = k or self.cfg.k
-        handles = [sh.dispatch_search(queries, k=k, params=params)
-                   for sh in self.shards]
-        return ShardedDispatch(handles, self.cfg.cap, k)
+        with jax.profiler.TraceAnnotation("lsmvec.search.dispatch"):
+            k = k or self.cfg.k
+            handles = [sh.dispatch_search(queries, k=k, params=params)
+                       for sh in self.shards]
+            return ShardedDispatch(handles, self.cfg.cap, k)
 
     def search(self, queries, k: Optional[int] = None, *,
                params: Optional[SearchParams] = None) -> SearchResult:

@@ -24,7 +24,7 @@ from repro.core import lsm, simhash
 from repro.core.backend import MemoryBreakdown
 from repro.core.iostats import IOStats
 from repro.core.traversal import BeamResult, beam_search, greedy_descent
-from repro.kernels.beam.ops import fused_beam_search
+from repro.kernels.beam.ops import beam_iter_cap, fused_beam_search
 from repro.kernels.gather_l2.ops import gather_l2, gather_l2_q8
 from repro.kernels.l2_distance.ops import l2_distance
 
@@ -474,11 +474,14 @@ def _search_batch_fused(cfg: HNSWConfig, state: HNSWState, qs: jax.Array,
         ef=ef, k=cfg.k, m_bits=cfg.m_bits, eps=cfg.eps, rho=rho,
         max_iters=2 * ef, use_filter=use_filter, n_expand=n_expand,
         record_heat=record_heat)
+    # the kernel's fori_loop runs every lane for the whole trip cap
+    trips = jnp.full(qs.shape[:1], beam_iter_cap(2 * ef, n_expand, ef),
+                     jnp.int32)
     res = BeamResult(
         ids, dists,
         IOStats(n_adj=stats[:, 0], n_vec=stats[:, 1],
                 n_filtered=stats[:, 2], n_hops=stats[:, 3]),
-        heat_nodes, heat_mask)
+        heat_nodes, heat_mask, trips)
     if cfg.tier:
         res = jax.vmap(lambda q, r: _tier_rerank(cfg, state, q, r))(qs, res)
     return res
@@ -501,6 +504,24 @@ def search_batch(cfg: HNSWConfig, state: HNSWState, qs: jax.Array,
         return jax.vmap(lambda q: search(cfg, state, q, **kw))(qs)
     return jax.vmap(lambda q, a: search(cfg, state, q, active=a, **kw))(
         qs, active)
+
+
+def beam_counters(res: BeamResult, *, ef: int, n_expand: int) -> jax.Array:
+    """A search batch's beam-loop counters, reduced on the device:
+    int32[3] = (trips, hops, slots).
+
+    `trips` is how many trips the batch's loop ran: the max over lanes
+    (the vmapped `while_loop` runs until its slowest lane stops; the
+    fused kernel runs its trip cap).  `hops` sums the lanes' expansions.
+    `slots` is the expansions the loop had room for: trips x lanes x
+    nodes popped per trip, padded lanes included, since the device runs
+    them — so hops / slots is the share of the loop's lanes that did
+    work, at most 1.
+    """
+    trips = jnp.max(res.trips).astype(jnp.int32)
+    width = res.trips.shape[0] * max(1, min(n_expand, ef))
+    return jnp.stack([trips, jnp.sum(res.stats.n_hops).astype(jnp.int32),
+                      trips * width])
 
 
 # ---------------------------------------------------------------------------

@@ -20,6 +20,7 @@ from repro.checkpoint import ckpt
 from repro.core import hnsw, iostats, lsm, reorder
 from repro.core.backend import (
     BackendStats,
+    BeamCounters,
     MaintenanceReport,
     MemoryBreakdown,
     SearchParams,
@@ -121,7 +122,8 @@ def jit_programs(cfg: hnsw.HNSWConfig) -> SimpleNamespace:
                                 n_expand=n_expand)
         heat_delta = _heat_delta(state, res) if record_heat \
             else jnp.zeros_like(state.heat)
-        return res, heat_delta
+        return res, heat_delta, hnsw.beam_counters(res, ef=ef,
+                                                   n_expand=n_expand)
 
     @functools.partial(jax.jit, static_argnames=("rho", "use_filter",
                                                  "ef", "n_expand",
@@ -135,7 +137,8 @@ def jit_programs(cfg: hnsw.HNSWConfig) -> SimpleNamespace:
                                 record_heat=record_heat)
         heat_delta = _heat_delta(state, res) if record_heat \
             else jnp.zeros_like(state.heat)
-        return res, heat_delta
+        return res, heat_delta, hnsw.beam_counters(res, ef=ef,
+                                                   n_expand=n_expand)
 
     @jax.jit
     def _resolve(state):
@@ -162,13 +165,16 @@ class DispatchedSearch:
 
     Holds the jit outputs without forcing a host sync; `collect()` is
     the one blocking point (`np.asarray`) and slices the padded batch
-    back to `[nq, k]` host-side.
+    back to `[nq, k]` host-side.  It also fetches the batch's beam-loop
+    counters (`hnsw.beam_counters`), three scalars the device reduced,
+    in the same sync.  The host time of `collect()` is the trace span
+    ``lsmvec.search.collect``.
     """
 
-    __slots__ = ("_ids", "_dists", "_nq", "_k")
+    __slots__ = ("_ids", "_dists", "_beam", "_nq", "_k")
 
-    def __init__(self, ids, dists, nq: int, k: int):
-        self._ids, self._dists = ids, dists
+    def __init__(self, ids, dists, beam, nq: int, k: int):
+        self._ids, self._dists, self._beam = ids, dists, beam
         self._nq, self._k = nq, k
 
     def is_ready(self) -> bool:
@@ -178,11 +184,13 @@ class DispatchedSearch:
             return True
 
     def collect(self) -> SearchResult:
-        with declared_sync("search result materialization"):
+        with jax.profiler.TraceAnnotation("lsmvec.search.collect"), \
+                declared_sync("search result materialization"):
             # sync-ok: collect() is the protocol's declared result sync point
             return SearchResult(
                 ids=np.asarray(self._ids)[:self._nq, :self._k],
-                dists=np.asarray(self._dists)[:self._nq, :self._k])
+                dists=np.asarray(self._dists)[:self._nq, :self._k],
+                beam=BeamCounters(*np.asarray(self._beam).tolist()))
 
 
 class LSMVecIndex:
@@ -410,34 +418,38 @@ class LSMVecIndex:
         the query batch to a fixed width with masked lanes so every call
         shares one traced shape (implies the snapshot path, which is
         where the mask-aware kernels live).
+
+        Its host time, up to the return, is the trace span
+        ``lsmvec.search.dispatch``.
         """
-        p = (params or SearchParams()).resolve(self.cfg)
-        k = k or self.cfg.k
-        qs_np = np.atleast_2d(np.asarray(queries, np.float32))
-        nq = len(qs_np)
-        if p.use_snapshot or p.pad_to is not None:
-            width = p.pad_to if p.pad_to else nq
-            if nq > width:
-                raise ValueError(f"batch {nq} exceeds pad width {width}")
-            padded = np.zeros((width, qs_np.shape[1]), np.float32)
-            padded[:nq] = qs_np
-            valid = np.arange(width) < nq
-            res, heat_delta = self._search_snap_fn(
-                self.state, jnp.asarray(padded), jnp.asarray(valid),
-                self.snapshot(), p.rho, p.use_filter, p.ef, p.n_expand,
-                p.record_heat)
-        else:
-            res, heat_delta = self._search_fn(
-                self.state, jnp.asarray(qs_np), p.rho, p.use_filter,
-                p.ef, p.n_expand, p.record_heat)
-        if p.record_heat:
-            self.state = self.state._replace(
-                heat=self.state.heat + heat_delta)
-        batch_stats = jax.tree.map(lambda a: jnp.sum(a), res.stats)
-        self.io_stats = self.io_stats + IOStats(*batch_stats)
-        # slicing happens host-side at collect(): device slicing would
-        # re-specialize on every distinct residual batch length
-        return DispatchedSearch(res.ids, res.dists, nq, k)
+        with jax.profiler.TraceAnnotation("lsmvec.search.dispatch"):
+            p = (params or SearchParams()).resolve(self.cfg)
+            k = k or self.cfg.k
+            qs_np = np.atleast_2d(np.asarray(queries, np.float32))
+            nq = len(qs_np)
+            if p.use_snapshot or p.pad_to is not None:
+                width = p.pad_to if p.pad_to else nq
+                if nq > width:
+                    raise ValueError(f"batch {nq} exceeds pad width {width}")
+                padded = np.zeros((width, qs_np.shape[1]), np.float32)
+                padded[:nq] = qs_np
+                valid = np.arange(width) < nq
+                res, heat_delta, beam = self._search_snap_fn(
+                    self.state, jnp.asarray(padded), jnp.asarray(valid),
+                    self.snapshot(), p.rho, p.use_filter, p.ef, p.n_expand,
+                    p.record_heat)
+            else:
+                res, heat_delta, beam = self._search_fn(
+                    self.state, jnp.asarray(qs_np), p.rho, p.use_filter,
+                    p.ef, p.n_expand, p.record_heat)
+            if p.record_heat:
+                self.state = self.state._replace(
+                    heat=self.state.heat + heat_delta)
+            batch_stats = jax.tree.map(lambda a: jnp.sum(a), res.stats)
+            self.io_stats = self.io_stats + IOStats(*batch_stats)
+            # slicing happens host-side at collect(): device slicing would
+            # re-specialize on every distinct residual batch length
+            return DispatchedSearch(res.ids, res.dists, beam, nq, k)
 
     def search(self, queries, k: Optional[int] = None, *,
                params: Optional[SearchParams] = None) -> SearchResult:

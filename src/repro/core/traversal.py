@@ -47,6 +47,11 @@ class BeamResult(NamedTuple):
     # is max_iters.  Callers reshape with (-1, ...), never a fixed size.
     heat_nodes: jax.Array   # int32[iter_cap * n_expand] — expanded nodes (-1 pad)
     heat_mask: jax.Array    # bool[iter_cap * n_expand, M] — fetched slots per hop
+    # while_loop trips this lane ran (0 on a masked lane).  Under vmap
+    # the loop runs until its slowest lane stops, so the batch's trips
+    # are the max over lanes; with n_expand > 1 a trip expands up to
+    # n_expand nodes, so trips and `stats.n_hops` differ.
+    trips: jax.Array        # int32[]
 
 
 def _rank_desc(score: jax.Array) -> jax.Array:
@@ -238,7 +243,7 @@ def beam_search(
 
     init = (jnp.int32(0), beam_ids, beam_d, expanded, visited, stats,
             heat_nodes, heat_mask)
-    (_, beam_ids, beam_d, _, _, stats, heat_nodes, heat_mask) = \
+    (trips, beam_ids, beam_d, _, _, stats, heat_nodes, heat_mask) = \
         jax.lax.while_loop(cond, body, init)
     if returnable is not None:
         # routable-but-not-returnable entries (tombstones) are demoted to
@@ -252,7 +257,7 @@ def beam_search(
         beam_ids = jnp.where(jnp.isfinite(beam_d), beam_ids[order], -1)
     return BeamResult(beam_ids, beam_d, stats,
                       heat_nodes.reshape(heat_len * B),
-                      heat_mask.reshape(heat_len * B, M))
+                      heat_mask.reshape(heat_len * B, M), trips)
 
 
 def greedy_descent(

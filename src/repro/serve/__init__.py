@@ -12,7 +12,8 @@ backends serve through the identical code path.
 - queue      — arrival-ordered coalescing queue (strict/relaxed modes)
 - scheduler  — ServeEngine: pad-and-mask dispatch, snapshot lifecycle,
   external-id ownership, adaptive batch shaping
-- metrics    — p50/p99 latency, occupancy, QPS, chosen windows
+- metrics    — p50/p99 latency, occupancy, queue wait, beam-loop
+  counters, chosen windows
 - maintenance— tombstone/heat thresholds -> consolidate()/compact()/
   reorder(), applied per shard (lazy-delete consolidation: DESIGN.md §9)
 - wal        — group-committed write-ahead log; with `ServeConfig.wal`

@@ -1,9 +1,14 @@
-"""Serving metrics: per-op latency quantiles, batch occupancy, QPS.
+"""Serving metrics: per-op latency quantiles, batch occupancy, queue wait,
+and the beam-loop counters of query batches.
 
 Latency is measured enqueue→completion (queueing + padding + device
 time), which is what a client of the engine actually observes.  Samples
 are kept in bounded reservoirs so a long-running engine never grows
 unboundedly; p50/p99 come from the retained sample.
+
+Queue wait (enqueue → the pump popping the request's batch) and the
+beam counters are raw sums beside the request and batch counts, so a
+reader differences two snapshots for the mean over any interval.
 """
 
 from __future__ import annotations
@@ -22,9 +27,13 @@ class ServeMetrics:
             op: collections.deque(maxlen=reservoir) for op in Op}
         self._count: Dict[Op, int] = {op: 0 for op in Op}
         self._batches: Dict[Op, int] = {op: 0 for op in Op}
-        self._occupancy: Dict[Op, int] = {op: 0 for op in Op}
-        self._t_start: float | None = None
-        self._t_last: float | None = None
+        #: seconds the `count` requests of each op waited in the queue,
+        #: summed: engine clock at the pop of a batch − each request's
+        #: enqueue time
+        self._queue_wait: Dict[Op, float] = {op: 0.0 for op in Op}
+        #: the backend's beam-loop counters (`BeamCounters`), summed over
+        #: the query batches that reported them (`batches`)
+        self.beam = {"batches": 0, "trips": 0, "hops": 0, "slots": 0}
         #: the coalescing window each op is currently running under —
         #: with adaptive batch shaping this tracks the arrival-rate EMA
         #: (DESIGN.md §10); static configs just echo their constants
@@ -48,14 +57,20 @@ class ServeMetrics:
         #: overlapped repair was in flight (relaxed mode; DESIGN.md §13)
         self.write_holds = 0
 
-    def record_batch(self, op: Op, n: int, latencies, now: float) -> None:
-        self._count[op] += n
+    def record_batch(self, op: Op, latencies, queue_wait: float) -> None:
+        """One executed batch: each request's enqueue→completion latency,
+        and the queue wait of its requests, summed."""
+        self._count[op] += len(latencies)
         self._batches[op] += 1
-        self._occupancy[op] += n
         self._lat[op].extend(latencies)
-        if self._t_start is None:
-            self._t_start = now
-        self._t_last = now
+        self._queue_wait[op] += queue_wait
+
+    def record_beam(self, beam) -> None:
+        """One query batch's `BeamCounters`."""
+        self.beam["batches"] += 1
+        self.beam["trips"] += beam.trips
+        self.beam["hops"] += beam.hops
+        self.beam["slots"] += beam.slots
 
     def _quantiles(self, op: Op):
         lat = np.asarray(self._lat[op], np.float64)
@@ -65,23 +80,20 @@ class ServeMetrics:
                 "p99_ms": float(np.percentile(lat, 99) * 1e3)}
 
     def snapshot(self) -> dict:
-        wall = 0.0
-        if self._t_start is not None and self._t_last is not None:
-            wall = max(self._t_last - self._t_start, 1e-9)
-        out: dict = {"wall_s": round(wall, 4),
-                     "snapshot_resolves": self.snapshot_resolves,
+        out: dict = {"snapshot_resolves": self.snapshot_resolves,
                      "delete_noops": self.delete_noops,
                      "write_holds": self.write_holds,
                      "maintenance": dict(self.maintenance_runs),
                      "wal": {"records": self.wal_records,
-                             "commits": self.wal_commits}}
+                             "commits": self.wal_commits},
+                     "beam": dict(self.beam)}
         for op in Op:
             nb = self._batches[op]
             out[op.value] = {
                 "count": self._count[op],
                 "batches": nb,
-                "mean_batch": round(self._occupancy[op] / nb, 2) if nb else 0.0,
-                "ops_per_s": round(self._count[op] / wall, 1) if wall else 0.0,
+                "mean_batch": round(self._count[op] / nb, 2) if nb else 0.0,
+                "queue_wait_s": self._queue_wait[op],
                 "window_ms": round(self.windows[op] * 1e3, 4),
                 **{k: round(v, 3) for k, v in self._quantiles(op).items()},
             }

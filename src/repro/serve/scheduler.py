@@ -262,6 +262,8 @@ class ServeEngine:
             qs, k=self.cfg.k,
             params=p.replace(use_snapshot=True,
                              pad_to=self.cfg.query_batch))
+        if res.beam is not None:
+            self.metrics.record_beam(res.beam)
         ext = np.where(res.ids >= 0,
                        self._int2ext[np.maximum(res.ids, 0)], -1)
         for row_ids, row_d, req in zip(ext, res.dists, reqs):
@@ -456,7 +458,8 @@ class ServeEngine:
             with self._lock:
                 if self.cfg.adaptive_windows:
                     self._shape_windows()
-                got = self.queue.next_batch(self.clock(), force=force,
+                t_pop = self.clock()
+                got = self.queue.next_batch(t_pop, force=force,
                                             hold_writes=hold)
                 held_writes = hold and (
                     self.queue.has_pending(Op.INSERT)
@@ -468,7 +471,8 @@ class ServeEngine:
                 # release the held writes normally
                 self._claim_overlap(block=True)
                 with self._lock:
-                    got = self.queue.next_batch(self.clock(), force=True)
+                    t_pop = self.clock()
+                    got = self.queue.next_batch(t_pop, force=True)
             if got is None:
                 # no batch released: still honor the group-commit clock
                 # so deferred acks can't wait behind an idle queue
@@ -504,7 +508,8 @@ class ServeEngine:
                 raise
             now = self.clock()
             self.metrics.record_batch(
-                op, len(reqs), [now - r.t_enqueue for r in reqs], now)
+                op, [now - r.t_enqueue for r in reqs],
+                sum(t_pop - r.t_enqueue for r in reqs))
             self.batch_log.append((op, len(reqs)))
             return op
 

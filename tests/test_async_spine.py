@@ -122,6 +122,14 @@ def test_dispatch_collect_matches_blocking_search(shards):
         assert isinstance(res, SearchResult)
         np.testing.assert_array_equal(res.ids, sync.ids)
         np.testing.assert_array_equal(res.dists, sync.dists)
+        assert res.beam == sync.beam and res.beam.hops > 0
+        if shards > 1:
+            # shards run side by side: the slowest shard's trips, the
+            # summed hops and slots
+            parts = [sh.search(queries, k=10).beam for sh in be.shards]
+            assert res.beam.trips == max(b.trips for b in parts)
+            assert res.beam.hops == sum(b.hops for b in parts)
+            assert res.beam.slots == sum(b.slots for b in parts)
         if phase == 0:
             _churn(be, seed=3)
 
@@ -139,6 +147,7 @@ def test_shards1_matches_bare_index_bitwise():
     b = sharded.search(queries, k=10)
     np.testing.assert_array_equal(a.ids, b.ids)
     np.testing.assert_array_equal(a.dists, b.dists)
+    assert a.beam == b.beam
 
 
 def test_dispatch_interleaving_does_not_change_results():

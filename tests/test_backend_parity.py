@@ -103,6 +103,7 @@ def test_both_backends_satisfy_the_protocol():
         res = b.search(base[:3], k=5)
         assert isinstance(res, SearchResult)
         assert res.ids.shape == res.dists.shape == (3, 5)
+        assert 0 < res.beam.hops <= res.beam.slots
         with pytest.raises(TypeError):
             ids, dists = res             # sequence compat is gone
         up = b.insert_batch(make_data(4, seed=1))
