@@ -60,6 +60,34 @@ def _rank_desc(score: jax.Array) -> jax.Array:
     return jnp.argsort(order, stable=True)
 
 
+def _total_order_key(d: jax.Array) -> jax.Array:
+    """int32 key whose signed order is the IEEE total order of f32 `d`
+    (-0.0 before 0.0, +inf last).  The map is its own inverse."""
+    bits = jax.lax.bitcast_convert_type(d, jnp.int32)
+    return jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+
+
+def merge_block(beam_ids, beam_d, expanded, cand_ids, cand_d, cand_exp):
+    """Merge a candidate block into the beam, keeping the beam's width.
+
+    Returns the first ef = len(beam_ids) rows of (ids, dists, expanded)
+    over beam + block in ascending distance, ties to the lower position —
+    exactly `top_k(-all_d, ef)`'s order.  One stable sort carries the ids
+    and flags as payload, so the order is never re-applied by a gather
+    (under a vmapped batch that is lanes x ef element gathers per trip).
+    The key is the total-order image of the distance, which `top_k` also
+    sorts by; a float key would be canonicalised (-0.0 == 0.0) by
+    `lax.sort`.
+    """
+    ef = beam_ids.shape[0]
+    key = _total_order_key(jnp.concatenate([beam_d, cand_d]))
+    ids = jnp.concatenate([beam_ids, cand_ids])
+    exp = jnp.concatenate([expanded, cand_exp]).astype(jnp.int32)
+    key, ids, exp = jax.lax.sort((key, ids, exp), num_keys=1, is_stable=True)
+    d = jax.lax.bitcast_convert_type(_total_order_key(key[:ef]), jnp.float32)
+    return ids[:ef], d, exp[:ef].astype(jnp.bool_)
+
+
 def beam_search(
     q: jax.Array,                    # f32[dim]
     entry: jax.Array,                # int32[] — entry node id
@@ -231,14 +259,10 @@ def beam_search(
         heat_mask = heat_mask.at[it].set(fetch_mask.reshape(B, M))
 
         # -- single merge of the whole block into the beam ------------------
-        all_ids = jnp.concatenate([beam_ids, fetch_ids])
-        all_d = jnp.concatenate([beam_d, dists])
-        all_exp = jnp.concatenate([expanded, jnp.ones((B * M,), jnp.bool_)])
-        # new candidates are unexpanded; mark masked ones expanded (inert)
-        all_exp = all_exp.at[ef:].set(~fetch_mask)
-        # top_k == stable argsort prefix here: ties prefer the lower index
-        _, order = jax.lax.top_k(-all_d, ef)
-        return (it + 1, all_ids[order], all_d[order], all_exp[order],
+        # new candidates are unexpanded; masked ones enter expanded (inert)
+        beam_ids, beam_d, expanded = merge_block(
+            beam_ids, beam_d, expanded, fetch_ids, dists, ~fetch_mask)
+        return (it + 1, beam_ids, beam_d, expanded,
                 visited, stats, heat_nodes, heat_mask)
 
     init = (jnp.int32(0), beam_ids, beam_d, expanded, visited, stats,

@@ -5,10 +5,10 @@ of queries as one fused JAX computation over dense operands.  It is the
 semantics contract for `kernel.py`'s Pallas megakernel and the CPU /
 interpret-host fallback that `ops.fused_beam_search` dispatches to.
 
-It mirrors `repro.core.traversal.beam_search` op for op (same loop trip
-structure, same stable `top_k` merges and tie-breaks, same
-SimHash/Hoeffding filter and sampling-rank math), specialized to the
-serving path's dense operands:
+It mirrors `repro.core.traversal.beam_search` step for step (same loop
+trip structure, same merge order and tie-breaks, which `top_k` gives here
+and a payload sort there, same SimHash/Hoeffding filter and
+sampling-rank math), specialized to the serving path's dense operands:
 
  - adjacency comes from a resolved snapshot (`lsm.snapshot_rows` view),
    i.e. `_snapshot_adj_fn` semantics — one gather per popped row,

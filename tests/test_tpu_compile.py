@@ -12,7 +12,9 @@ one process may load the TPU library, and every test worker imports this
 file.
 """
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -62,6 +64,44 @@ def _compile(fn, *args):
     return compiled
 
 
+def _beam_body_gathers(hlo, lanes, cap, ef):
+    """Instructions of the beam `while` body (the loop that carries the
+    `[lanes, cap + 1]` visited set), the computations it calls included,
+    whose op_name ends in `while/body/gather` and whose result has
+    lanes x ef elements: a beam-wide permutation applied by element
+    gathers on every trip."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        m = re.match(r"(?:ENTRY )?%(\S+) \(.*\{$", line)
+        if m:
+            name = m.group(1)
+            comps[name] = []
+        elif name is not None:
+            comps[name].append(line)
+    carry = f"pred[{lanes},{cap + 1}]"
+    todo = [re.search(r"body=%([\w.\-]+)", line).group(1)
+            for lines in comps.values() for line in lines
+            if " while(" in line and carry in line.split(" while(")[0]]
+    assert todo, "no beam while loop carrying the visited set"
+    seen, hits = set(), []
+    while todo:
+        c = todo.pop()
+        if c in seen:
+            continue
+        seen.add(c)
+        for line in comps[c]:
+            todo += re.findall(r"(?:calls|to_apply|body|condition)=%([\w.\-]+)",
+                               line)
+            for group in re.findall(r"branch_computations=\{([^}]*)\}", line):
+                todo += re.findall(r"%([\w.\-]+)", group)
+            m = re.match(r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]", line)
+            if m and re.search(r'op_name="[^"]*while/body/gather"', line):
+                shape = [int(x) for x in m.group(1).split(",") if x]
+                if math.prod(shape) == lanes * ef:
+                    hits.append(line.strip())
+    return hits
+
+
 def test_gather_l2_compiles(one_chip):
     _compile(lambda q, t, i: gather_ops.gather_l2(
                  q, t, i, use_pallas=True, interpret=False),
@@ -91,7 +131,8 @@ def test_batched_search_compiles(one_chip, on_tpu, program):
     """The index's own jitted batched search at cap 2^20, as dispatched
     (`search`: LSM-probe adjacency; `search_snap`: the snapshot path
     `ServeEngine` serves from), with the fused gather kernel inside the
-    vmapped beam loop."""
+    vmapped beam loop.  The beam merge applies its order inside the sort:
+    no lanes x ef element gather is left in the loop body."""
     cfg = hnsw.HNSWConfig(cap=CAP, dim=DIM)
     state = jax.tree.map(
         lambda a: _shape(one_chip, a.shape, a.dtype),
@@ -107,4 +148,7 @@ def test_batched_search_compiles(one_chip, on_tpu, program):
             state, qs, _shape(one_chip, (QUERY_BATCH,), jnp.bool_),
             _shape(one_chip, (CAP, cfg.M), jnp.int32), **statics)
     compiled = lowered.compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    gathers = _beam_body_gathers(hlo, QUERY_BATCH, CAP, cfg.ef_search)
+    assert not gathers, gathers[:3]
